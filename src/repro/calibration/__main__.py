@@ -17,8 +17,9 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro import compile_cache
 from repro.calibration import registry
-from repro.calibration.calibrate import calibrate
+from repro.calibration.calibrate import calibrate, measured_device
 from repro.core.model import ModelSchemaError
 
 
@@ -71,6 +72,11 @@ def main(argv=None) -> int:
         print(model.interpretation_report())
         return 0
 
+    compile_cache.enable_jax_cache()
+    dev = measured_device()
+    print(f"# measuring on platform={dev['platform']} "
+          f"device_kind={dev['device_kind']} "
+          f"device_count={dev['device_count']} as {args.device!r}")
     classes = ([c.strip() for c in args.classes.split(",") if c.strip()]
                if args.classes else None)
     result = calibrate(

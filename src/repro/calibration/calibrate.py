@@ -1,7 +1,10 @@
 """The calibration driver — the paper's fit-once-per-device loop, end to end.
 
 One call (or ``python -m repro.calibration``) runs the full black-box
-procedure of §4 on the *current* runtime device:
+procedure of §4 on the *current* runtime device, whatever backend JAX
+found; the fitted model's ``meta`` records that device (``platform``,
+``device_kind``, ``device_count``), so the registry name alone never
+vouches for the hardware:
 
   1. measure launch overhead (empty-kernel floor, §4.2);
   2. time the 9-class measurement-kernel suite (``core.mkernels``) under the
@@ -21,6 +24,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
+import jax
+
 from repro.calibration import registry
 from repro.core import fit, measure, mkernels
 from repro.core.model import LinearCostModel
@@ -34,6 +39,13 @@ class CalibrationResult:
     registry_path: Optional[str]     # None when write_registry=False
     wall_s: float
     labels: List[str] = field(default_factory=list)
+
+
+def measured_device() -> Dict[str, object]:
+    """The device the measurements run on, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
 
 
 def calibrate(device: str = "cpu", *, scale: str = "cpu",
@@ -83,6 +95,7 @@ def calibrate(device: str = "cpu", *, scale: str = "cpu",
 
     model = fit.fit_relative(pvs, times, device=device, ridge=ridge,
                              nonneg=nonneg)
+    model.meta.update(measured_device())
     model.meta.update({
         "scale": scale, "runs": runs, "drop": drop,
         "launch_overhead_s": launch,

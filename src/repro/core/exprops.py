@@ -816,16 +816,16 @@ DISK_STATS = _RegistryStats(
 def compile_cache_dir() -> Optional[str]:
     """The on-disk program cache directory, or None when disabled.
 
-    ``REPRO_COMPILE_CACHE`` overrides the default
-    ``~/.cache/repro/exprops``; set it to ``0``/``off``/``none`` to
-    disable persistence entirely."""
+    ``REPRO_COMPILE_CACHE`` overrides the default ``<checkout>/.cache/
+    exprops`` (``repro.compile_cache.CACHE_ROOT``); set it to
+    ``0``/``off``/``none`` to disable persistence entirely."""
     v = os.environ.get("REPRO_COMPILE_CACHE")
     if v is not None:
         if v.strip().lower() in ("", "0", "off", "none", "disabled"):
             return None
         return v
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "exprops")
+    from repro.compile_cache import CACHE_ROOT
+    return str(CACHE_ROOT / "exprops")
 
 
 _EPOCH_MODULES = ("repro.core.symcount", "repro.core.archcount",

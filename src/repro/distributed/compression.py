@@ -80,9 +80,7 @@ def psum_compressed(x: jnp.ndarray, axis: str, chunk: int = CHUNK
       2. dequantize + sum the n received contributions (my reduced shard);
       3. re-quantize, ``all_gather`` (int8), dequantize.
     """
-    # jax.lax.axis_size only exists in newer JAX; psum(1) is the portable form
-    n = jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size") \
-        else jax.lax.psum(1, axis)
+    n = jax.lax.axis_size(axis)
     flat, size = _pad_to(x.astype(jnp.float32), n * chunk)
     shards = flat.reshape(n, -1)  # row i -> destined for rank i
 

@@ -24,20 +24,6 @@ from typing import Mapping, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as _pltpu
-
-# --- version shim -----------------------------------------------------------
-# The TPU compiler-params record was renamed across JAX releases:
-# ``pltpu.TPUCompilerParams`` (≤0.4.x) became ``pltpu.CompilerParams``
-# (≥0.5).  All kernel modules route through this alias so they run on both.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")
-
-
-def compiler_params(*, dimension_semantics: Tuple[str, ...]):
-    """Build TPU compiler params portably across JAX versions."""
-    return CompilerParams(dimension_semantics=dimension_semantics)
-
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import matmul as _mm

@@ -26,50 +26,64 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import compiler_params
+
+def _dot(a, b, contract):
+    """f32 MXU contraction at full precision.  The state carries f32 sums
+    across every chunk, and Mosaic's default precision for f32 operands
+    left the final state up to 9e-3 off the O(L) recurrence (TPU v5e,
+    mamba2-370m widths, L=2048)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
             n_chunks: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, 0].astype(jnp.float32)      # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)    # (Q,)
-    A = a_ref[0].astype(jnp.float32)         # scalar (negative)
-    Bm = b_ref[0, 0].astype(jnp.float32)     # (Q, N)
-    Cm = c_ref[0, 0].astype(jnp.float32)     # (Q, N)
+    # Every per-step vector stays 2-D: Mosaic lays out (sublane, lane)
+    # tiles, and a chunk's dt/cumsum is needed both as a row (1, Q) and as
+    # a column (Q, 1).
+    x = x_ref[0, 0].astype(jnp.float32)                  # (Q, P)
+    dt = dt_ref[0, 0, pl.ds(ci, 1), :].astype(jnp.float32)  # (1, Q)
+    A = a_ref[hi]                                        # SMEM scalar (< 0)
+    Bm = b_ref[0, 0].astype(jnp.float32)                 # (Q, N)
+    Cm = c_ref[0, 0].astype(jnp.float32)                 # (Q, N)
 
-    dA = dt * A                              # (Q,) ≤ 0
-    cum = jnp.cumsum(dA)                     # (Q,)
     Q = x.shape[0]
-
-    diff = cum[:, None] - cum[None, :]       # (Q, Q)
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    eye = ii == jj
+    dA = dt * A                                          # (1, Q) ≤ 0
+    # cumsum as a masked lane reduction (no cumsum lowering on Mosaic);
+    # row <-> column moves go through the diagonal
+    cum_c = jnp.sum(jnp.where(jj <= ii, dA, 0.0), axis=1,
+                    keepdims=True)                       # (Q, 1)
+    cum_r = jnp.sum(jnp.where(eye, cum_c, 0.0), axis=0,
+                    keepdims=True)                       # (1, Q)
+    dt_c = jnp.sum(jnp.where(eye, dt, 0.0), axis=1, keepdims=True)  # (Q, 1)
+    total = jnp.sum(dA, axis=1, keepdims=True)           # (1, 1) = cum[-1]
+
     # mask the exponent, not the product (avoids inf·0 in the bwd pass)
-    diff = jnp.where(ii >= jj, diff, -1e30)
+    diff = jnp.where(ii >= jj, cum_c - cum_r, -1e30)     # (Q, Q)
     Lmat = jnp.exp(diff)
 
-    CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (Q, Q)
-    W = CB * Lmat * dt[None, :]
-    y_intra = jax.lax.dot_general(W, x, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+    CB = _dot(Cm, Bm, ((1,), (1,)))                      # (Q, Q)
+    W = CB * Lmat * dt
+    y_intra = _dot(W, x, ((1,), (0,)))                   # (Q, P)
 
-    h_prev = h_scr[...]                      # (P, N)
-    y_inter = jax.lax.dot_general(
-        Cm * jnp.exp(cum)[:, None], h_prev,
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    h_prev = h_scr[...]                                  # (P, N)
+    y_inter = _dot(Cm * jnp.exp(cum_c), h_prev, ((1,), (1,)))  # (Q, P)
 
-    decay_end = jnp.exp(cum[-1] - cum)       # (Q,)
-    Bw = Bm * (dt * decay_end)[:, None]      # (Q, N)
-    S_c = jax.lax.dot_general(x, Bw, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (P, N)
-    h_new = h_prev * jnp.exp(cum[-1]) + S_c
+    decay_end = jnp.exp(total - cum_c)                   # (Q, 1)
+    Bw = Bm * (dt_c * decay_end)                         # (Q, N)
+    S_c = _dot(x, Bw, ((0,), (0,)))                      # (P, N)
+    h_new = h_prev * jnp.exp(total) + S_c
     h_scr[...] = h_new
 
     y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
@@ -99,8 +113,10 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = True):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, ci: (b, h, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, ci: (b, h, ci)),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,)),
+            # a head's whole dt as one (nc, chunk) tile — a legal TPU block
+            # for any chunk; the kernel reads its chunk's row
+            pl.BlockSpec((1, 1, nc, chunk), lambda b, h, ci: (b, h, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, N),
                          lambda b, h, ci, rep=rep: (b, h // rep, ci, 0)),
             pl.BlockSpec((1, 1, chunk, N),
@@ -115,10 +131,10 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = True):
             jax.ShapeDtypeStruct((Bz, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A, B, C)
+    )(x, dt.reshape(Bz, H, nc, chunk), A.astype(jnp.float32), B, C)
     return y, h_fin
 
 

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import compiler_params
-
 
 def _kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].T
@@ -33,7 +31,7 @@ def transpose(x, *, block: int = 256, interpret: bool = True) -> jnp.ndarray:
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((N, M), x.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)

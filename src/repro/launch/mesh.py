@@ -9,25 +9,13 @@ import jax
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """Version-portable ``jax.make_mesh``.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg) only exist in
-    newer JAX releases; on older ones (e.g. 0.4.37) a plain ``Mesh`` is the
-    same thing — every axis defaults to Auto.  All mesh construction in the
-    repo (and the multidevice tests' subprocess bodies) routes through here.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names), **kwargs)
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    GSPMD).  All mesh construction in the repo (and the multidevice tests'
+    subprocess bodies) routes through here."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -7,10 +7,12 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional, Sequence
 
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.registry import ARCHS, get_arch
 from repro.models import transformer
 from repro.obs import metrics as _obs_metrics
@@ -18,7 +20,7 @@ from repro.obs import trace as _obs_trace
 from repro.runtime.server import DecodeServer, Request
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--reduced", action="store_true")
@@ -51,15 +53,40 @@ def main() -> None:
     ap.add_argument("--max-queue", type=int, default=None,
                     help="shed queued requests beyond this depth "
                          "(stamped with retry-after)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.trace_json:
-        _obs_trace.enable(process_name="serve")
 
+def build_server(args: argparse.Namespace, injector=None) -> DecodeServer:
+    """The server ``args`` describe, with weights drawn from ``--seed``."""
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     params, _ = transformer.init_params(cfg, jax.random.PRNGKey(args.seed))
+    return DecodeServer(cfg, params, slots=args.slots,
+                        max_len=args.max_len, seed=args.seed,
+                        admission=args.admission,
+                        slo_decode_s=args.slo_decode_s,
+                        injector=injector)
+
+
+def make_requests(args: argparse.Namespace, vocab_size: int
+                  ) -> List[Request]:
+    """``--requests`` prompts of 4–16 random tokens each, from ``--seed``."""
+    rng = np.random.default_rng(args.seed)
+    reqs = []
+    for rid in range(args.requests):
+        plen = int(rng.integers(4, 17))
+        prompt = rng.integers(2, vocab_size, size=plen).astype(np.int32)
+        reqs.append(Request(rid=rid, prompt=prompt, max_new=args.max_new))
+    return reqs
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    compile_cache.enable_jax_cache()
+    if args.trace_json:
+        _obs_trace.enable(process_name="serve")
+
     supervised = args.supervise or args.fault_plan
     injector = None
     if supervised:
@@ -69,17 +96,9 @@ def main() -> None:
         injector = FaultInjector(fplan)
         if fplan:
             print(f"[serve] fault plan armed: {fplan.describe()}")
-    server = DecodeServer(cfg, params, slots=args.slots,
-                          max_len=args.max_len, seed=args.seed,
-                          admission=args.admission,
-                          slo_decode_s=args.slo_decode_s,
-                          injector=injector)
-
-    rng = np.random.default_rng(args.seed)
-    for rid in range(args.requests):
-        plen = int(rng.integers(4, 17))
-        prompt = rng.integers(2, cfg.vocab_size, size=plen).astype(np.int32)
-        server.submit(Request(rid=rid, prompt=prompt, max_new=args.max_new))
+    server = build_server(args, injector)
+    for req in make_requests(args, server.cfg.vocab_size):
+        server.submit(req)
 
     t0 = time.perf_counter()
     if supervised:

@@ -12,10 +12,12 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+from typing import Optional, Sequence, Tuple
 
 import jax
 
-from repro.configs.base import SHAPES
+from repro import compile_cache
+from repro.configs.base import SHAPES, ArchConfig
 from repro.configs.registry import ARCHS, get_arch
 from repro.data.pipeline import DataConfig
 from repro.obs import metrics as _obs_metrics
@@ -25,7 +27,7 @@ from repro.core import predictor
 from repro.distributed.plan import plan_for
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--steps", type=int, default=100)
@@ -73,11 +75,12 @@ def main() -> None:
     ap.add_argument("--watchdog-k", type=float, default=6.0,
                     help="watchdog deadline = k x max(predicted, median)")
     ap.add_argument("--max-recoveries", type=int, default=8)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.trace_json:
-        _obs_trace.enable(process_name="train")
 
+def trainer_config(args: argparse.Namespace
+                   ) -> Tuple[ArchConfig, DataConfig, TrainerConfig]:
+    """The (arch, data, trainer) configs a ``Trainer`` is built from."""
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -89,6 +92,16 @@ def main() -> None:
                        online_calibrate=args.online_calibrate,
                        calib_device=args.calib_device,
                        calib_auto_register=args.calib_auto_register)
+    return cfg, dc, tc
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    compile_cache.enable_jax_cache()
+    if args.trace_json:
+        _obs_trace.enable(process_name="train")
+
+    cfg, dc, tc = trainer_config(args)
 
     # cost-model prediction for the straggler monitor threshold
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=args.seq,

@@ -216,8 +216,9 @@ class DecodeServer:
     def _n_active(self) -> int:
         return sum(r is not None for r in self.active)
 
-    def _prefill_slot(self, slot: int, req: Request) -> None:
-        """Feed the prompt token-by-token into this slot's cache rows.
+    def _prefill_slot(self, slot: int, req: Request) -> jnp.ndarray:
+        """Feed the prompt token-by-token into this slot's cache rows;
+        returns this slot's logits after the last token fed.
 
         (A production server prefills with one chunked forward; the decode
         loop here is the clear-and-correct path for the CPU example, and
@@ -239,6 +240,7 @@ class DecodeServer:
         self.active[slot] = req
         self.remaining[slot] = req.max_new - len(req.out)
         self._ctx[slot] = len(req.prompt) + len(req.out)
+        return logits[slot, -1]
 
     def evict_slot(self, slot: int) -> Optional[Request]:
         """Evict ``slot``'s request back to the FRONT of the queue (it has

@@ -152,7 +152,6 @@ def make_manual_dp_train_step(cfg: ArchConfig, optimizer: opt.Optimizer,
 
     The error-feedback residual rides in the extended opt state.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import compression as comp
 
@@ -183,11 +182,11 @@ def make_manual_dp_train_step(cfg: ArchConfig, optimizer: opt.Optimizer,
         p_spec = jax.tree.map(lambda _: P(), state.params)
         ef_spec = jax.tree.map(lambda _: P(), ef)
         b_spec = jax.tree.map(lambda _: P(axis), batch)
-        grads, ef_new, l = shard_map(
+        grads, ef_new, l = jax.shard_map(
             local_body, mesh=mesh,
             in_specs=(p_spec, ef_spec, b_spec, P()),
             out_specs=(p_spec, ef_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(state.params, ef, batch, state.step)
         grads, gnorm = opt.clip_by_global_norm(grads, clip_norm)
         lr = lr_schedule(state.step)

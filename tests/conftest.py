@@ -1,7 +1,7 @@
 """Suite-wide fixtures.
 
 The fused-program disk cache (``core.exprops``) defaults to
-``~/.cache/repro/exprops``; tests must neither litter the user's real
+``<checkout>/.cache/exprops``; tests must neither litter that shared
 cache nor read stale programs from it (which would couple test outcomes
 to machine state), so the whole session is pointed at a throwaway
 directory.  Individual tests that probe the cache behavior override the

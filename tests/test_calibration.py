@@ -230,10 +230,15 @@ def test_calibrate_tiny_end_to_end(tmp_path):
                     verbose=False)
     assert res.registry_path and os.path.exists(res.registry_path)
     assert res.model.meta["source"] == "calibrated"
+    import jax
+    assert res.model.meta["platform"] == jax.devices()[0].platform
+    assert res.model.meta["device_kind"] == jax.devices()[0].device_kind
+    assert res.model.meta["device_count"] == jax.device_count()
     assert res.report["n"] == len(res.labels) > 0
 
     loaded = load_model("cpu-test", str(tmp_path))
     assert np.array_equal(loaded.weights, res.model.weights)
+    assert loaded.meta["platform"] == res.model.meta["platform"]
     pv = {k: float(i + 1) for i, k in enumerate(res.model.keys)}
     assert loaded.predict(pv) == res.model.predict(pv)
 

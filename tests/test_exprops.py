@@ -23,6 +23,7 @@ probes), warm/cold disk-cache reporting.
 from __future__ import annotations
 
 import json
+import os
 import random
 
 import numpy as np
@@ -436,6 +437,11 @@ def test_disk_cache_disabled_and_report(monkeypatch):
     monkeypatch.setenv("REPRO_COMPILE_CACHE", "/tmp/somewhere")
     assert exprops.compile_cache_dir() == "/tmp/somewhere"
     assert exprops.disk_cache_report().startswith("compile cache:")
+    # unset: a fixed directory inside the checkout, beside JAX's cache
+    monkeypatch.delenv("REPRO_COMPILE_CACHE")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert exprops.compile_cache_dir() == os.path.join(root, ".cache",
+                                                       "exprops")
 
 
 def test_program_key_changes_with_inputs():

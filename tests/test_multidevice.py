@@ -34,7 +34,6 @@ def run8(body: str, timeout: int = 420) -> str:
 def test_psum_compressed_matches_fp32_psum():
     run8("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import compression as comp
 
@@ -47,8 +46,8 @@ def test_psum_compressed_matches_fp32_psum():
         approx = comp.psum_compressed(xs, 'data')
         return exact, approx
 
-    f = shard_map(body, mesh=mesh, in_specs=(P('data'),),
-                  out_specs=(P(), P()), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P('data'),),
+                      out_specs=(P(), P()), check_vma=False)
     exact, approx = f(x.reshape(8, 1, 4096))
     rel = float(jnp.max(jnp.abs(exact - approx))
                 / jnp.max(jnp.abs(exact)))

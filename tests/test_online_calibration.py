@@ -445,12 +445,17 @@ def test_calibrator_fits_residual_head_on_refit(tmp_path, make_drift_stream):
 # ---------------------------------------------------------------------------
 
 
-def test_cli_measure_fit_register_load_roundtrip(tmp_path, capsys):
+def test_cli_measure_fit_register_load_roundtrip(tmp_path, capsys,
+                                                 monkeypatch):
     from repro.calibration.__main__ import main
+    # a set JAX_COMPILATION_CACHE_DIR keeps the CLI off the checkout cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax"))
     reg = str(tmp_path / "cli-reg")
     rc = main(["--device", "cli-dev", "--scale", "tiny", "--runs", "3",
                "--drop", "1", "--classes", "stride1_global", "--out", reg])
     assert rc == 0
+    first = capsys.readouterr().out.splitlines()[0]
+    assert first.startswith("# measuring on platform=cpu "), first
     m1 = registry.load_model("cli-dev", reg)
     assert m1.meta["source"] == "calibrated"
     # register -> load -> re-register -> load is bit-exact (no decimal decay)
