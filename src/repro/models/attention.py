@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import logical
 from repro.models import layers
+from repro.obs import scopes
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -296,6 +297,7 @@ def _flash_xla_bwd(causal, window, scale, chunk_q, chunk_kv, res, do):
 _flash_xla.defvjp(_flash_xla_fwd, _flash_xla_bwd)
 
 
+@scopes.scoped(scopes.ATTENTION_CORE)
 def attention_core(q, k, v, *, causal: bool = True,
                    window: Optional[int] = None,
                    q_offset: int = 0,
@@ -355,6 +357,7 @@ def _positions_for(cfg, B, S, offset=0):
     return pos
 
 
+@scopes.scoped(scopes.ATTENTION)
 def attn_apply(p, x, cfg, *, positions=None,
                cache: Optional[KVCache] = None,
                cache_pos: Optional[jnp.ndarray] = None):
@@ -393,12 +396,13 @@ def attn_apply(p, x, cfg, *, positions=None,
             o = jnp.repeat(v, H // KVH, axis=2)
         elif flags.pallas_enabled():
             from repro.kernels import ops as kops
-            o = kops.flash_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), causal=True,
-                window=cfg.sliding_window,
-                block_sizes="auto",  # cost-model-chosen tiling (autotune)
-            ).transpose(0, 2, 1, 3)
+            with jax.named_scope(scopes.ATTENTION_CORE):
+                o = kops.flash_attention(
+                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3), causal=True,
+                    window=cfg.sliding_window,
+                    block_sizes="auto",  # cost-model-chosen tiling
+                ).transpose(0, 2, 1, 3)
         elif cp > 1:
             # context parallelism: n_heads % tp != 0, so attention divides
             # over the model axis by q-SLICE instead of by head; k/v stay

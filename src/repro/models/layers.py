@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import scopes
+
 Params = dict
 Axes = dict
 
@@ -67,6 +69,7 @@ def ffn_init(key, d_model: int, d_ff: int, dtype):
     return p, a
 
 
+@scopes.scoped(scopes.MLP)
 def ffn(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     g = dense(p["gate"], x)
     u = dense(p["up"], x)
@@ -120,6 +123,7 @@ def tied_lm_head(embed_p: Params, x: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+@scopes.scoped(scopes.LM_HEAD_LOSS)
 def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray,
                  mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """Mean cross-entropy; logits (..., V) in any float dtype (f32 math)."""

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import logical
 from repro.models import layers
+from repro.obs import scopes
 
 GROUP_TOKENS = 2048  # dispatch group size (tokens)
 
@@ -41,6 +42,7 @@ def _capacity(tokens_per_group: int, E: int, top_k: int, factor: float) -> int:
     return max(c, 4)
 
 
+@scopes.scoped(scopes.MOE)
 def moe_apply(p, x: jnp.ndarray, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
     mcfg = cfg.moe

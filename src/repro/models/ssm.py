@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import logical
 from repro.models import layers
+from repro.obs import scopes
 
 
 class SSMState(NamedTuple):
@@ -70,6 +71,7 @@ def _causal_conv(xbc, w, b):
     return jax.nn.silu(out + b)
 
 
+@scopes.scoped(scopes.SSD_SCAN)
 def _ssd_chunked(x, dt, A, Bm, Cm, chunk: int, h0=None):
     """Chunked SSD.
 
@@ -136,6 +138,7 @@ def _ssd_chunked(x, dt, A, Bm, Cm, chunk: int, h0=None):
     return y, h_fin
 
 
+@scopes.scoped(scopes.SSM)
 def ssm_apply(p, x, cfg, *, state: SSMState | None = None
               ) -> Tuple[jnp.ndarray, SSMState | None]:
     """Mamba2 block.  x (B, S, d).  With ``state``, runs one decode step."""
@@ -162,10 +165,11 @@ def ssm_apply(p, x, cfg, *, state: SSMState | None = None
         from repro.runtime import flags
         if flags.pallas_enabled():
             from repro.kernels import ops as kops
-            y, _ = kops.ssd_scan(
-                xin.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A,
-                Bm.transpose(0, 2, 1, 3), Cm.transpose(0, 2, 1, 3),
-                chunk=chunk, block_sizes="auto")
+            with jax.named_scope(scopes.SSD_SCAN):
+                y, _ = kops.ssd_scan(
+                    xin.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A,
+                    Bm.transpose(0, 2, 1, 3), Cm.transpose(0, 2, 1, 3),
+                    chunk=chunk, block_sizes="auto")
             y = y.transpose(0, 2, 1, 3)
         else:
             y, _ = _ssd_chunked(xin, dt, A, Bm, Cm, chunk)

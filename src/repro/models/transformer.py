@@ -23,6 +23,7 @@ from repro.configs.base import ArchConfig
 from repro.distributed.sharding import logical
 from repro.models import attention as attn
 from repro.models import layers, moe, ssm
+from repro.obs import scopes
 
 Params = Dict[str, Any]
 
@@ -180,6 +181,7 @@ def embed_inputs(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray]):
     return _annotate_resid(h)
 
 
+@scopes.scoped(scopes.LM_HEAD_LOSS)
 def logits_from_hidden(params, cfg: ArchConfig, h):
     h = layers.rmsnorm(params["final_ln"], h, cfg.norm_eps)
     if cfg.tie_embeddings:

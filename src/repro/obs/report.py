@@ -8,25 +8,16 @@ surface: every human-readable status line flows through ``emit``, which
 
   * formats the canonical ``[tag] key=value key=value …`` layout
     (``format_line``), so every line is machine-greppable the same way;
-  * counts the emission in the metrics registry
-    (``repro_report_lines_total{tag=…}``), so a run's report volume is
-    itself observable;
   * prints through an injectable printer (tests pass a capture list, the
-    disabled path passes ``printer=None`` to format-and-count only).
+    disabled path passes ``printer=None`` to format only).
 
-Zero dependencies; imports only the sibling ``metrics`` module.
+Zero dependencies.
 """
 from __future__ import annotations
 
 from typing import Callable, Mapping, Optional
 
-from repro.obs import metrics
-
 __all__ = ["format_fields", "format_line", "emit"]
-
-_LINES = metrics.REGISTRY.counter(
-    "repro_report_lines_total",
-    "structured [tag] report lines emitted, by tag")
 
 
 def _fmt_value(v: object) -> str:
@@ -54,9 +45,8 @@ def format_line(tag: str, fields: Optional[Mapping[str, object]] = None,
 def emit(tag: str, fields: Optional[Mapping[str, object]] = None,
          text: str = "",
          printer: Optional[Callable[[str], None]] = print) -> str:
-    """Format, count, and (optionally) print one report line; returns it."""
+    """Format and (optionally) print one report line; returns it."""
     line = format_line(tag, fields, text)
-    _LINES.inc(1, tag=tag)
     if printer is not None:
         printer(line)
     return line

@@ -19,21 +19,33 @@ Usage::
 
 Spans nest via a per-thread stack; completed spans record (name, start,
 duration, depth, predicted seconds, free-form args).  A **disabled**
-tracer is a true no-op: ``span()`` returns one shared null context
-manager, no clock is read, nothing allocates — the near-zero-overhead
-path production code keeps on by default (``benchmarks/fused_bench.py``
-holds it to ≤2% on the fused scoring hot path).
+tracer records nothing: no clock is read and nothing allocates — the
+near-zero-overhead path production code keeps on by default
+(``benchmarks/fused_bench.py`` holds it to ≤2% on the fused scoring hot
+path).
+
+Whether or not the tracer records, every span also enters a
+``jax.profiler.TraceAnnotation`` of its name while a ``jax.profiler``
+session is running, so the span lands on the host plane of that session's
+trace, on the clock its device ops share.  ``span(...,
+profiler_step=True)`` makes it a ``StepTraceAnnotation`` instead, whose
+``step_num`` the caller gives with ``set(step_num=n)``; ``set``'s keywords
+reach the annotation's metadata.  Without a session (or before JAX is
+imported) a disabled tracer's ``span()`` returns one shared null context
+manager.
 
 The module-level tracer (``get_tracer`` / ``set_tracer``) is what library
 code consults; it defaults to a disabled instance, and CLI entry points
 swap in an enabled one under ``--trace-json``.
 
-Zero dependencies; imports nothing from the rest of ``repro``.
+Imports nothing from the rest of ``repro``, and never imports JAX: a
+process that has not imported it has no profiler session to annotate.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -100,20 +112,67 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str, step: bool):
+    """A ``jax.profiler`` annotation named ``name`` (a step annotation if
+    ``step``), or None where no profiler session is running."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return None
+    if step:
+        return profiler.StepTraceAnnotation(name)
+    return profiler.TraceAnnotation(name)
+
+
+def _set_metadata(ann, predicted_s, kw) -> None:
+    if predicted_s is not None:
+        kw["predicted_s"] = float(predicted_s)
+    if kw:
+        ann.set_metadata(**kw)
+
+
+class _AnnotatedSpan:
+    """A disabled tracer's span while a profiler session runs: the
+    annotation alone."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, predicted_s: Optional[float] = None, **kw) -> None:
+        _set_metadata(self._ann, predicted_s, kw)
+
+    predicted_s = None
+    duration_s = None
+
+
 class _LiveSpan:
     """Context manager recording one span into its tracer."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_ann")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, ann):
         self._tracer = tracer
         self.span = span
+        self._ann = ann
 
     def __enter__(self) -> "_LiveSpan":
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._tracer._finish(self.span)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
     def set(self, predicted_s: Optional[float] = None, **kw) -> None:
@@ -122,6 +181,8 @@ class _LiveSpan:
         if predicted_s is not None:
             self.span.predicted_s = float(predicted_s)
         self.span.args.update(kw)
+        if self._ann is not None:
+            _set_metadata(self._ann, predicted_s, kw)
 
     @property
     def predicted_s(self):
@@ -153,7 +214,6 @@ class Tracer:
         self.spans: List[Span] = []        # completed spans
         self.instants: List[Span] = []     # zero-duration marker events
         self.process_name = process_name
-        self.dropped = 0                   # spans opened while disabled
 
     # -- recording ---------------------------------------------------------
     def _stack(self) -> list:
@@ -163,18 +223,21 @@ class Tracer:
         return st
 
     def span(self, name: str, *, predicted_s: Optional[float] = None,
-             **args):
-        """Open a nested span; use as a context manager.  On a disabled
-        tracer this returns the shared null span — no clock read, no
-        allocation."""
+             profiler_step: bool = False, **args):
+        """Open a nested span; use as a context manager.  It also enters a
+        profiler annotation of ``name`` while a ``jax.profiler`` session
+        runs (a step annotation with ``profiler_step``).  On a disabled
+        tracer this returns the bare annotation, or the shared null span
+        without a session — no clock read, no allocation."""
+        ann = _annotation(name, profiler_step)
         if not self.enabled:
-            return _NULL_SPAN
+            return _NULL_SPAN if ann is None else _AnnotatedSpan(ann)
         st = self._stack()
         sp = Span(name, self._clock() - self._epoch, len(st),
                   None if predicted_s is None else float(predicted_s),
                   dict(args))
         st.append(sp)
-        return _LiveSpan(self, sp)
+        return _LiveSpan(self, sp, ann)
 
     def _finish(self, sp: Span) -> None:
         st = self._stack()

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.distributed.plan import Plan
 from repro.models import transformer
+from repro.obs import scopes
 from repro.optim import optimizers as opt
 
 
@@ -77,10 +78,11 @@ def make_train_step(cfg: ArchConfig, optimizer: opt.Optimizer,
                 loss, has_aux=True)(state.params, batch)
             grads = _pin_grads(grads)
 
-        grads, gnorm = opt.clip_by_global_norm(grads, clip_norm)
-        lr = lr_schedule(state.step)
-        new_params, new_opt = optimizer.update(grads, state.opt_state,
-                                               state.params, lr)
+        with jax.named_scope(scopes.OPTIMIZER):
+            grads, gnorm = opt.clip_by_global_norm(grads, clip_norm)
+            lr = lr_schedule(state.step)
+            new_params, new_opt = optimizer.update(grads, state.opt_state,
+                                                   state.params, lr)
         new_state = TrainState(new_params, new_opt, state.step + 1)
         return new_state, {"loss": loss_val, "grad_norm": gnorm,
                            "lr": jnp.float32(lr)}

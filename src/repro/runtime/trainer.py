@@ -137,69 +137,88 @@ class Trainer:
               ) -> List[Dict[str, float]]:
         tracer = _obs_trace.get_tracer()
         for _ in range(n_steps):
-            step = self.step
-            if self.injector is not None:
-                # may corrupt state files or raise DeviceLossError — BEFORE
-                # the step runs, so the supervisor resumes at exactly this
-                # step and batch semantics stay exact
-                self.injector.step_begin(step)
-            batch = {k: jnp.asarray(v)
-                     for k, v in self.loader.batch(step).items()}
-            # the model's prediction for THIS step — the straggler monitor
-            # carries it (re-anchored on every refit), so the span's
-            # predicted overlay tracks the live model, not the launch one
-            pred_s = self.monitor.predicted_step_s
-            t0 = time.perf_counter()
-            with tracer.span("train_step", predicted_s=pred_s, step=step):
-                self.state, metrics = self.step_fn(self.state, batch)
-                jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            if self.injector is not None:
-                # scheduled slowdowns/spikes scale the OBSERVED time: the
-                # monitor, watchdog, histogram and calibrator all see the
-                # same perturbed measurement, as they would a real straggler
-                dt = self.injector.perturb_step_time(step, dt)
-            _STEP_SECONDS.observe(dt)
-            self.monitor.observe(step, [dt])
-            if self.calibrator is not None:
-                sample = dt
+            # the profiler's step; its children name what the host does
+            # while the device waits (docs/OBSERVABILITY.md)
+            with tracer.span("train.iter", profiler_step=True) as it:
+                with tracer.span("train.sync"):
+                    step = self.step
+                it.set(step_num=step)
                 if self.injector is not None:
-                    sample = self.injector.perturb_telemetry(step, dt)
-                ev = self.calibrator.observe(self._step_pv, sample,
-                                             step=step,
-                                             tag="train", phase="train")
-                if ev is not None:
-                    # refit already happened inside observe(); re-anchor the
-                    # straggler threshold to the refit model's prediction
-                    self.monitor.reanchor(
-                        self.calibrator.model.predict(self._step_pv))
-                    _obs_report.emit(
-                        "calib",
-                        text=f"drift detected at step {step} "
-                             f"(direction={ev.direction}, onset seq "
-                             f"{ev.onset_seq}): refit epoch "
-                             f"{self.calibrator.refits}, revision "
-                             f"{self.calibrator.revision}")
+                    # may corrupt state files or raise DeviceLossError —
+                    # BEFORE the step runs, so the supervisor resumes at
+                    # exactly this step and batch semantics stay exact
+                    self.injector.step_begin(step)
+                with tracer.span("train.batch"):
+                    batch = {k: jnp.asarray(v)
+                             for k, v in self.loader.batch(step).items()}
+                # the model's prediction for THIS step — the straggler
+                # monitor carries it (re-anchored on every refit), so the
+                # span's predicted overlay tracks the live model, not the
+                # launch one
+                pred_s = self.monitor.predicted_step_s
+                t0 = time.perf_counter()
+                with tracer.span("train_step", predicted_s=pred_s,
+                                 step=step):
+                    with tracer.span("train.dispatch"):
+                        self.state, metrics = self.step_fn(self.state, batch)
+                    with tracer.span("train.wait"):
+                        jax.block_until_ready(metrics["loss"])
+                dt = time.perf_counter() - t0
+                if self.injector is not None:
+                    # scheduled slowdowns/spikes scale the OBSERVED time:
+                    # the monitor, watchdog, histogram and calibrator all
+                    # see the same perturbed measurement, as they would a
+                    # real straggler
+                    dt = self.injector.perturb_step_time(step, dt)
+                _STEP_SECONDS.observe(dt)
+                self.monitor.observe(step, [dt])
+                if self.calibrator is not None:
+                    with tracer.span("train.calib"):
+                        sample = dt
+                        if self.injector is not None:
+                            sample = self.injector.perturb_telemetry(step,
+                                                                     dt)
+                        ev = self.calibrator.observe(
+                            self._step_pv, sample, step=step, tag="train",
+                            phase="train")
+                        if ev is not None:
+                            # refit already happened inside observe();
+                            # re-anchor the straggler threshold to the
+                            # refit model's prediction
+                            self.monitor.reanchor(
+                                self.calibrator.model.predict(
+                                    self._step_pv))
+                            _obs_report.emit(
+                                "calib",
+                                text=f"drift detected at step {step} "
+                                     f"(direction={ev.direction}, onset "
+                                     f"seq {ev.onset_seq}): refit epoch "
+                                     f"{self.calibrator.refits}, revision "
+                                     f"{self.calibrator.revision}")
 
-            m = {"step": step, "loss": float(metrics["loss"]),
-                 "grad_norm": float(metrics["grad_norm"]),
-                 "lr": float(metrics["lr"]), "time_s": dt}
-            self.history.append(m)
-            if on_metrics:
-                on_metrics(step, m)
-            elif step % self.tc.log_every == 0:
-                _obs_report.emit(
-                    "trainer",
-                    text=f"step {step:5d} loss {m['loss']:.4f} "
-                         f"gnorm {m['grad_norm']:.3f} {dt*1e3:.0f}ms")
-            if self.calibrator is not None \
-                    and step % self.tc.log_every == 0:
-                _obs_report.emit("calib",
-                                 text=self.calibrator.report_line())
-            if self.tc.ckpt_dir and (step + 1) % self.tc.ckpt_every == 0:
-                self._save()
+                with tracer.span("train.pull"):
+                    m = {"step": step, "loss": float(metrics["loss"]),
+                         "grad_norm": float(metrics["grad_norm"]),
+                         "lr": float(metrics["lr"]), "time_s": dt}
+                self.history.append(m)
+                if on_metrics:
+                    on_metrics(step, m)
+                elif step % self.tc.log_every == 0:
+                    _obs_report.emit(
+                        "trainer",
+                        text=f"step {step:5d} loss {m['loss']:.4f} "
+                             f"gnorm {m['grad_norm']:.3f} {dt*1e3:.0f}ms")
+                if self.calibrator is not None \
+                        and step % self.tc.log_every == 0:
+                    _obs_report.emit("calib",
+                                     text=self.calibrator.report_line())
+                if self.tc.ckpt_dir and (step + 1) % self.tc.ckpt_every == 0:
+                    with tracer.span("train.ckpt"):
+                        self._save()
         if self.tc.ckpt_dir and self.tc.save_on_exit:
-            self._save(blocking=True)
+            with tracer.span("train.ckpt"):
+                self._save(blocking=True)
         elif self.ckpt is not None:
-            self.ckpt.wait()
+            with tracer.span("train.ckpt"):
+                self.ckpt.wait()
         return self.history

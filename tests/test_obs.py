@@ -175,6 +175,86 @@ def test_disabled_tracer_is_noop():
     assert tr.report_lines() == []
 
 
+def _host_events(logdir):
+    """(name, stats) of every host-plane event of the one profile under
+    ``logdir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_lands_on_the_profiler_host_plane(tmp_path, enabled):
+    import jax
+
+    tr = obs_trace.Tracer(enabled=enabled, clock=FakeClock())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("probe.outer") as sp:
+            sp.set(rows=7)
+            with tr.span("probe.inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    events = dict(_host_events(tmp_path))
+    assert "probe.outer" in events and "probe.inner" in events
+    assert events["probe.outer"]["rows"] == 7
+    assert [s.name for s in tr.spans] == (
+        ["probe.inner", "probe.outer"] if enabled else [])
+
+
+def test_disabled_tracer_records_nothing_under_the_profiler(tmp_path):
+    import jax
+
+    clk = FakeClock()
+    tr = obs_trace.Tracer(enabled=False, clock=clk)
+    epoch_calls = clk.calls
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("probe", predicted_s=1.0, x=1) as sp:
+            sp.set(predicted_s=2.0, y=3)
+        tr.instant("marker")
+    finally:
+        jax.profiler.stop_trace()
+    assert clk.calls == epoch_calls
+    assert tr.spans == [] and tr.instants == []
+    # outside a profiler session the shared null span is back
+    assert tr.span("a") is tr.span("b")
+
+
+def test_trainer_iteration_is_a_profiler_step(tmp_path):
+    import jax
+
+    from repro.data.pipeline import DataConfig
+    from repro.runtime.trainer import Trainer, TrainerConfig
+
+    cfg = ARCHS["smollm-360m"].reduced()
+    trainer = Trainer(cfg, DataConfig(vocab_size=cfg.vocab_size, seq_len=32,
+                                      global_batch=2),
+                      TrainerConfig(log_every=1000))
+    trainer.train(1)                          # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        trainer.train(2)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    iters = [st for name, st in events if name == "train.iter"]
+    assert [st["step_num"] for st in iters] == [1, 2]
+    names = {name for name, _ in events}
+    for want in ("train.sync", "train.batch", "train_step",
+                 "train.dispatch", "train.wait", "train.pull"):
+        assert want in names, want
+    assert "train.ckpt" not in names and "train.calib" not in names
+
+
 def test_module_tracer_default_disabled_and_swap():
     assert obs_trace.get_tracer().enabled is False
     t = obs_trace.Tracer(clock=FakeClock(), process_name="t")
@@ -464,7 +544,6 @@ def test_process_registry_has_framework_families():
     for name in ("repro_basis_cache_hits_total",
                  "repro_compile_cache_events_total",
                  "repro_telemetry_samples_total",
-                 "repro_report_lines_total",
                  "repro_lru_evictions_total"):
         assert name in text, name
 
@@ -497,12 +576,10 @@ def test_report_emit_format_and_counting():
                            text="policy=model", printer=got.append)
     assert line == "[admit] rid=3 score=1.25 pred=0.006ms policy=model"
     assert got == [line]
-    before = obs_metrics.REGISTRY.counter(
-        "repro_report_lines_total").value(tag="quiet")
     assert obs_report.emit("quiet", printer=None) == "[quiet]"
-    after = obs_metrics.REGISTRY.counter(
-        "repro_report_lines_total").value(tag="quiet")
-    assert after == before + 1
+    assert obs_report.emit("t", text="only text", printer=got.append) \
+        == "[t] only text"
+    assert got[-1] == "[t] only text"
 
 
 # ---------------------------------------------------------------------------
