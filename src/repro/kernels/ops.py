@@ -1,9 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
 ``interpret`` defaults to True on CPU (kernel bodies execute in Python for
-validation) and False on TPU (compiled for the MXU/VMEM target).  Model code
-calls these wrappers; swapping the XLA production path for the Pallas hot
-path is a Plan-level switch (``Plan.use_pallas`` in the runtime).
+validation) and False on TPU (compiled for the MXU/VMEM target).
+``models.attention.attention_core`` calls ``flash_attention`` on a TPU
+for training and prefill attention; the SSD mixer calls ``ssd_scan``
+under ``runtime.flags.use_pallas``.
 
 Every wrapper accepts ``block_sizes``:
 
@@ -72,19 +73,25 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     block_sizes: BlockSizes = None, model=None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
-    """q (B,H,Sq,dh) × k,v (B,KVH,Skv,dh) → (B,H,Sq,dh)."""
+    """q (B,H,Sq,dh) × k,v (B,KVH,Skv,dh) → (B,H,Sq,dh), differentiable.
+
+    Blocks below one lane width (128) are raised to it where the length
+    allows: the compiled kernels keep row statistics in (G, block_q)
+    blocks, block_q on the lanes."""
     if interpret is None:
         interpret = _default_interpret()
     B, H, Sq, dh = q.shape
-    shape = {"B": B, "H": H, "KVH": k.shape[1], "Sq": Sq, "Skv": k.shape[2],
+    KVH, Skv = k.shape[1], k.shape[2]
+    shape = {"B": B, "H": H, "KVH": KVH, "Sq": Sq, "Skv": Skv,
              "dh": dh, "causal": causal, "window": window,
              "bits": _dtype_bits(q.dtype)}
     blocks = _resolve_blocks("flash_attention", shape, block_sizes,
                              {"block_q": block_q, "block_k": block_k}, model)
+    for name, n in (("block_q", Sq), ("block_k", Skv)):
+        if n % _fa.NUM_LANES == 0:
+            blocks[name] = max(blocks[name], _fa.NUM_LANES)
     return _flash_attention_jit(q, k, v, causal=causal, window=window,
-                                block_q=blocks["block_q"],
-                                block_k=blocks["block_k"],
-                                interpret=interpret)
+                                interpret=interpret, **blocks)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

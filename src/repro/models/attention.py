@@ -2,10 +2,13 @@
 softmax (pure-XLA flash-attention analog used by the distributed lowering),
 and KV-cache decode.
 
-The Pallas flash-attention kernel in ``repro.kernels.flash_attention`` is the
-TPU hot-path implementation of the same contraction; ``attention_core`` here
-is both the XLA production path (it lowers on any backend and keeps peak
-memory to O(chunk²)) and the reference the kernel is validated against.
+``attention_core`` picks the path from what it can observe: on a TPU,
+training and prefill attention (square, offset 0, aligned lengths, one
+device) run through the Pallas flash kernels of
+``repro.kernels.flash_attention``, forward and backward; everything else
+(the CPU, decode, context-parallel slices, ragged lengths) takes the XLA
+paths here, which lower on any backend and are what the kernels are
+validated against.
 """
 from __future__ import annotations
 
@@ -17,8 +20,10 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.distributed import sharding
 from repro.distributed.sharding import logical
 from repro.models import layers
+from repro.obs import metrics as obs_metrics
 from repro.obs import scopes
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -297,6 +302,38 @@ def _flash_xla_bwd(causal, window, scale, chunk_q, chunk_kv, res, do):
 _flash_xla.defvjp(_flash_xla_fwd, _flash_xla_bwd)
 
 
+_LOWERINGS = obs_metrics.REGISTRY.counter(
+    "repro_attention_lowerings_total",
+    "attention_core traces by the path they lowered to "
+    "(pallas_flash, xla_chunked, xla_plain)")
+
+#: smallest kernel block (positions): one lane width
+_KERNEL_ALIGN = 128
+
+
+def _kernel_path(q, k, q_offset) -> bool:
+    """The Pallas kernels take the call: a TPU, one device, square
+    self-attention from position 0 (a Python int), lengths a multiple of
+    the smallest block and a head dim a multiple of 64."""
+    Sq, dh = q.shape[1], q.shape[3]
+    ctx = sharding.current()
+    return (jax.default_backend() == "tpu"
+            and (ctx is None or ctx.mesh.size == 1)
+            and type(q_offset) is int and q_offset == 0
+            and Sq == k.shape[1] and Sq > 1
+            and Sq % _KERNEL_ALIGN == 0 and dh % 64 == 0)
+
+
+def _pallas_attention(q, k, v, causal, window):
+    """The kernels in their (B, heads, S, dh) layout, with the
+    autotuner's blocks (the forward's and the backward's best alike on a
+    v5e at smollm-360m's 7 × 2048, PERF.md §6)."""
+    from repro.kernels import ops as kops
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    return t(kops.flash_attention(t(q), t(k), t(v), causal=causal,
+                                  window=window, block_sizes="auto"))
+
+
 @scopes.scoped(scopes.ATTENTION_CORE)
 def attention_core(q, k, v, *, causal: bool = True,
                    window: Optional[int] = None,
@@ -306,22 +343,29 @@ def attention_core(q, k, v, *, causal: bool = True,
     """q (B,Sq,H,dh) × k,v (B,Skv,KVH,dh) -> (B,Sq,H,dh).
 
     ``q_offset``: absolute position of q[0] (decode: cache length).
-    Dispatches to the materialized path for small problems and the
-    online-softmax chunked path for long sequences.
+    Dispatches to the Pallas flash kernels where ``_kernel_path`` allows,
+    else to the materialized path for small problems and the
+    online-softmax chunked path for long sequences.  Each trace counts
+    its path in ``repro_attention_lowerings_total``.
     """
     B, Sq, H, dh = q.shape
     Skv = k.shape[1]
     scale = 1.0 / math.sqrt(dh)
+    if _kernel_path(q, k, q_offset):
+        _LOWERINGS.inc(path="pallas_flash")
+        return _pallas_attention(q, k, v, causal, window)
     q_pos = q_offset + jnp.arange(Sq)
     k_pos = jnp.arange(Skv)
     big = Sq * Skv > 2048 * 2048
     if (big or force_chunked) and Sq % 512 == 0 and Skv % 512 == 0 \
             and Sq > 1:
+        _LOWERINGS.inc(path="xla_chunked")
         cq = min(chunk_q, Sq)
         ck = min(chunk_kv, Skv)
         start = jnp.asarray(q_offset, jnp.float32) \
             if not isinstance(q_offset, jax.Array) else q_offset
         return _flash_xla(q, k, v, start, causal, window, scale, cq, ck)
+    _LOWERINGS.inc(path="xla_plain")
     return _plain_attention(q, k, v, q_pos, k_pos, causal, window, scale)
 
 
@@ -394,15 +438,6 @@ def attn_apply(p, x, cfg, *, positions=None,
         cp = context_parallel_factor(H, S)
         if flags.attention_stubbed():  # cost-attribution mode
             o = jnp.repeat(v, H // KVH, axis=2)
-        elif flags.pallas_enabled():
-            from repro.kernels import ops as kops
-            with jax.named_scope(scopes.ATTENTION_CORE):
-                o = kops.flash_attention(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                    v.transpose(0, 2, 1, 3), causal=True,
-                    window=cfg.sliding_window,
-                    block_sizes="auto",  # cost-model-chosen tiling
-                ).transpose(0, 2, 1, 3)
         elif cp > 1:
             # context parallelism: n_heads % tp != 0, so attention divides
             # over the model axis by q-SLICE instead of by head; k/v stay

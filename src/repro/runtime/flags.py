@@ -1,9 +1,9 @@
 """Runtime feature flags (thread-local, context-managed).
 
-``use_pallas()`` switches the attention / SSD mixers from their XLA
-production paths to the Pallas TPU kernels (interpret-mode on CPU).  The
-two paths are numerically equivalent (tests assert it); the flag exists so
-the dry-run/CPU paths stay fast while TPU deployments take the kernel path.
+``use_pallas()`` switches the SSD mixer from its XLA chunked path to the
+Pallas ``ssd_scan`` kernel (interpret-mode on CPU); the two are
+numerically equivalent (tests assert it).  Attention has no flag:
+``attention_core`` takes its Pallas kernels on a TPU by itself.
 """
 from __future__ import annotations
 

@@ -9,9 +9,13 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention, schedule_props as fa_props
+from repro.kernels.flash_attention import (flash_attention,
+                                           flash_attention_lse,
+                                           schedule_props as fa_props)
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models import attention as attn_mod
 from repro.models import ssm as ssm_mod
+from repro.obs import metrics as obs_metrics
 
 KEY = jax.random.PRNGKey(42)
 
@@ -74,6 +78,153 @@ def test_flash_attention_schedule_props_skip_count():
     from repro.core import properties as props
     assert p_c[props.mxu_key(16)] < 0.6 * p_f[props.mxu_key(16)]
     assert p_c[props.BARRIER] == p_f[props.BARRIER]  # grid still walks
+
+
+def _bhsd(B, H, KVH, Sq, Skv, dh, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (B, H, Sq, dh), jnp.float32),
+            jax.random.normal(ks[1], (B, KVH, Skv, dh), jnp.float32),
+            jax.random.normal(ks[2], (B, KVH, Skv, dh), jnp.float32),
+            jax.random.normal(ks[3], (B, H, Sq, dh), jnp.float32))
+
+
+def _plain_bhsd(q, k, v, causal, window):
+    """``attention._plain_attention`` (the XLA path) in (B, H, S, dh)."""
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    o = attn_mod._plain_attention(
+        t(q), t(k), t(v), jnp.arange(q.shape[2]), jnp.arange(k.shape[2]),
+        causal, window, 1.0 / np.sqrt(q.shape[-1]))
+    return t(o)
+
+
+@pytest.mark.parametrize("KVH,window", [(4, None), (2, None), (4, 96),
+                                        (2, 96)],
+                         ids=["causal-G1", "causal-G2", "window-G1",
+                              "window-G2"])
+def test_flash_attention_grads_match_plain(KVH, window):
+    """dq, dk, dv of the backward kernels = jax.grad of the f32 XLA path."""
+    q, k, v, w = _bhsd(1, 4, KVH, 256, 256, 64)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    ours = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=128, block_k=128,
+        interpret=True)), (0, 1, 2))(q, k, v)
+    theirs = jax.grad(loss(lambda q, k, v: _plain_bhsd(
+        q, k, v, True, window)), (0, 1, 2))(q, k, v)
+    for a, b in zip(ours, theirs):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_flash_attention_lse_matches_logsumexp():
+    q, k, v, _ = _bhsd(1, 4, 2, 256, 256, 64)
+    o, lse = flash_attention_lse(q, k, v, causal=True, window=96,
+                                 block_q=128, block_k=128, interpret=True)
+    kg = jnp.repeat(k, 2, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, kg,
+                   precision=jax.lax.Precision.HIGHEST) / 8.0
+    pos = jnp.arange(256)
+    vis = (pos[:, None] >= pos[None, :]) & (pos[:, None] - pos[None, :] < 96)
+    want = jax.nn.logsumexp(jnp.where(vis, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(
+        _plain_bhsd(q, k, v, True, 96)), atol=1e-5, rtol=1e-5)
+
+
+def test_flash_attention_fully_masked_rows_are_zero():
+    """Queries past the keys' window see no key: zero output, lse +inf,
+    and finite gradients that are zero for those rows."""
+    q, k, v, w = _bhsd(1, 2, 1, 256, 128, 64)
+    kw = dict(causal=False, window=64, block_q=128, block_k=128,
+              interpret=True)
+    o, lse = flash_attention_lse(q, k, v, **kw)
+    hidden = np.arange(256) - 127 >= 64          # no key within the window
+    assert hidden.any() and not hidden.all()
+    np.testing.assert_array_equal(np.asarray(o)[:, :, hidden], 0.0)
+    assert np.isposinf(np.asarray(lse)[:, :, hidden]).all()
+    assert np.isfinite(np.asarray(lse)[:, :, ~hidden]).all()
+    grads = jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, **kw) * w), (0, 1, 2))(q, k, v)
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
+    np.testing.assert_array_equal(np.asarray(grads[0])[:, :, hidden], 0.0)
+
+
+def _lowerings():
+    c = obs_metrics.REGISTRY.counter("repro_attention_lowerings_total")
+    return {p: c.value(path=p)
+            for p in ("pallas_flash", "xla_chunked", "xla_plain")}
+
+
+def _took(before):
+    after = _lowerings()
+    return {p for p in after if after[p] > before[p]}
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """``attention_core`` sees a TPU; the kernels still run interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_default_interpret", lambda: True)
+
+
+def _bshd(B, S, H, KVH, dh, Skv=None):
+    ks = jax.random.split(KEY, 3)
+    Skv = S if Skv is None else Skv
+    return (jax.random.normal(ks[0], (B, S, H, dh), jnp.float32),
+            jax.random.normal(ks[1], (B, Skv, KVH, dh), jnp.float32),
+            jax.random.normal(ks[2], (B, Skv, KVH, dh), jnp.float32))
+
+
+def test_attention_core_takes_the_kernels_on_tpu(tpu_backend):
+    """An aligned training shape runs the Pallas path, forward and
+    backward, with the XLA path's numbers."""
+    q, k, v = _bshd(1, 256, 4, 2, 64)
+    before = _lowerings()
+
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(attn_mod.attention_core(q, k, v)))
+
+    val, grads = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+    assert _took(before) == {"pallas_flash"}
+    pos = jnp.arange(256)
+    want_val, want = jax.value_and_grad(lambda q, k, v: jnp.sum(jnp.sin(
+        attn_mod._plain_attention(q, k, v, pos, pos, True, None, 0.125))),
+        (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(want_val), rtol=1e-5)
+    for a, b in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_attention_core_keeps_xla_where_the_kernels_do_not_fit(tpu_backend):
+    """Decode (one query at a cache offset), a length off the block, and
+    offsets traced under the context-parallel vmap take the XLA paths."""
+    cases = {
+        "decode": lambda: attn_mod.attention_core(
+            *_bshd(2, 1, 4, 2, 64, Skv=256), q_offset=255),
+        "unaligned": lambda: attn_mod.attention_core(*_bshd(1, 200, 4, 2,
+                                                            64)),
+        "vmapped offsets": lambda: jax.vmap(
+            lambda qq, off: attn_mod.attention_core(
+                qq, *_bshd(1, 256, 4, 2, 64)[1:], q_offset=off),
+            in_axes=(1, 0), out_axes=1)(
+                _bshd(1, 256, 4, 2, 64)[0].reshape(1, 2, 128, 4, 64),
+                jnp.arange(2, dtype=jnp.float32) * 128),
+    }
+    for name, run in cases.items():
+        before = _lowerings()
+        jax.eval_shape(run)
+        took = _took(before)
+        assert took and "pallas_flash" not in took, name
+
+
+def test_attention_core_keeps_xla_on_cpu():
+    before = _lowerings()
+    jax.eval_shape(lambda: attn_mod.attention_core(*_bshd(1, 256, 4, 2, 64)))
+    assert _took(before) == {"xla_plain"}
 
 
 # ---------------------------------------------------------------------------

@@ -94,6 +94,32 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_attention_core_grad_compiles_for_v5e(one_chip, no_persistent_cache,
+                                              monkeypatch):
+    """jax.grad through ``attention_core`` on the TPU path at smollm-360m's
+    attention (1 × 15 (5 kv) × 2048 × 64, bf16): the forward kernel and
+    both backward kernels are in the program, under ``attention_core``."""
+    from repro.models import attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sm, S = get_arch("smollm-360m"), 2048
+    q = jax.ShapeDtypeStruct((1, S, sm.n_heads, sm.head_dim_), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, S, sm.n_kv_heads, sm.head_dim_),
+                              jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(attention.attention_core(q, k, v).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+        ).as_text()
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    fwd = [c for c in calls if "transpose(" not in c]
+    bwd = [c for c in calls if "transpose(" in c]
+    assert len(fwd) >= 1 and len(bwd) >= 2, (len(fwd), len(bwd))
+    assert all("attention_core" in c for c in calls)
+
+
 def test_compile_cache_dir_is_fixed_in_checkout(monkeypatch):
     prev = jax.config.jax_compilation_cache_dir
     try:
