@@ -10,6 +10,29 @@ window then calls ``train`` a step at a time for ``--seconds``; with
 ``--trace 1`` it traces a few steps instead.  After the window the
 program's state is freed and the float32 reference follows the checked
 steps over the same weights and documents.
+
+A ``--trace 1`` run also compiles the step afresh once the window has
+closed (``program_trace.compile_step``: the HLO whose metadata names the
+program's scopes, and the step's ``memory_analysis()``), and hands every
+per-layer reader (``bench/metrics/<metric>.py``, a ``read(ctx)`` that
+returns a number, or None where it finds nothing to read) one ``ctx``:
+
+- ``reduced``: ``tracing.Reduced``, the device's busy time, idle gaps and
+  top operations in the window of the benchmark's own spans (None where
+  the trace holds no step or no device operation);
+- ``program``: ``program_trace.ProgramTrace``, the program's own host
+  spans and the device operations by the named scope they ran under (None
+  where the fresh compile or the trace's load failed; the failure is
+  logged);
+- ``flops_per_step``: the reference's model FLOPs of one step;
+- ``tokens_per_step``: rows × sequence length;
+- ``peaks``: the chip's entry of ``peaks.json``;
+- ``ref``: the configuration's reference module (``bench/models/``);
+- ``config``, ``traffic``: the configuration's and the traffic mix's
+  files, as loaded.
+
+A reader of a new configuration's scopes is then a new file, with no edit
+here.
 """
 from __future__ import annotations
 
@@ -20,13 +43,14 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Any, Dict, List
+import traceback
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import check, spec, tracing, traffic
+from bench import check, program_trace, spec, tracing, traffic
 from bench.models import common
 
 #: window steps traced in a ``--trace 1`` run
@@ -241,6 +265,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     failed = sum(1 for h in window if not math.isfinite(h["loss"]))
     stats = [d.memory_stats() or {} for d in devs]
     peak_bytes = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
+    if trace:
+        hlo_text, step_memory = _compile_step(trainer, t_start)
     del trainer
     ses.trainer = None
     gc.collect()
@@ -248,21 +274,32 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs), "memory_peak_bytes": peak_bytes}
     if trace:
-        red = tracing.reduce(tracing.load(tracing.find_xplane(logdir),
-                                          _device_lines(dev)))
+        xplane = tracing.find_xplane(logdir)
+        red = tracing.reduce(tracing.load(xplane, _device_lines(dev)))
+        program = _program_trace(xplane, hlo_text, dev, t_start)
         shutil.rmtree(logdir, ignore_errors=True)
         ctx = {"flops_per_step": ses.flops_step, "peaks": peaks,
-               "reduced": red}
+               "reduced": red, "program": program, "ref": ses.ref,
+               "config": ses.config, "traffic": ses.mix,
+               "tokens_per_step": ses.rows * ses.seq}
         metrics = {}
         for m in cell.per_layer:
             value = cell.reader(m["name"]).read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        if step_memory is not None:
+            device["step_memory_bytes"] = step_memory
         if red is not None:
             device["busy_s"] = red.busy_s
             device["window_s"] = red.window_s
             result["breakdown"] = {"device_ops": red.device_ops,
                                    "idle_gaps": red.idle_gaps}
+            if program is not None:
+                idle = sorted(program_trace.idle_by_span(program).items(),
+                              key=lambda kv: -kv[1])[:tracing.TOP]
+                result["breakdown"].update(
+                    scopes=program_trace.by_scope(program),
+                    idle_by_span=[[n, t] for n, t in idle])
     else:
         tokens = len(window) * ses.rows * ses.seq
         metrics = {"train_tokens_per_s": {"value": tokens / window_s,
@@ -279,6 +316,39 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     return {"correct": failed == 0 and check.passed(checks),
             "attempted": len(window), "failed": failed, "metrics": metrics,
             "device": device, **result, "checks": checks}
+
+
+def _compile_step(trainer, t_start: float
+                  ) -> Tuple[Optional[str], Optional[Dict[str, int]]]:
+    """The step's HLO text and memory analysis from one fresh compile after
+    the traced window; (None, None), logged, where it fails: the traced
+    run's other readings do not need it."""
+    t0 = time.perf_counter()
+    try:
+        step = program_trace.compile_step(trainer)
+        out = step.as_text(), program_trace.memory_bytes(
+            step.memory_analysis())
+    except Exception:
+        log(t_start, "the step's fresh compile failed; no program trace\n"
+            + traceback.format_exc())
+        return None, None
+    log(t_start, f"step compiled afresh in {time.perf_counter() - t0:.3f}s, "
+                 f"memory {out[1]}")
+    return out
+
+
+def _program_trace(xplane: str, hlo_text: Optional[str], dev, t_start: float
+                   ) -> Optional[program_trace.ProgramTrace]:
+    """The program's spans and scoped device operations, or None, logged,
+    where there is no step HLO or the trace does not load."""
+    if hlo_text is None:
+        return None
+    try:
+        return program_trace.load(xplane, hlo_text, _device_lines(dev))
+    except Exception:
+        log(t_start, "the program trace did not load\n"
+            + traceback.format_exc())
+        return None
 
 
 def _device_lines(dev):

@@ -109,10 +109,19 @@ def block_loss(p, c, tokens, labels, mask, ops: common.Ops):
     return common.head_xent_sum(x, p["embed"]["w"], labels, mask, ops)
 
 
+def attention_flops_per_token(c: Dict[str, Any], seq_len: int) -> float:
+    """Model FLOPs of one trained token's causal attention, forward and
+    backward: q·k and p·v over an average of ``seq_len / 2`` keys in every
+    layer, whatever implements them."""
+    L, H, dh = (c["num_hidden_layers"], c["num_attention_heads"],
+                c["head_dim"])
+    return 6.0 * L * 2 * H * dh * (seq_len / 2)
+
+
 def flops_per_token(c: Dict[str, Any], seq_len: int) -> float:
     """Model FLOPs of one trained token, forward and backward (3 × the
     forward's 2 × multiply-adds): every projection, the MLP, the tied LM
-    head, and causal attention over an average of ``seq_len / 2`` keys.
+    head, and causal attention (``attention_flops_per_token``).
     Recomputation does not count; the embedding lookup is a gather."""
     d, ff, V, L = (c["hidden_size"], c["intermediate_size"], c["vocab_size"],
                    c["num_hidden_layers"])
@@ -120,6 +129,5 @@ def flops_per_token(c: Dict[str, Any], seq_len: int) -> float:
                  c["head_dim"])
     proj = d * H * dh + 2 * d * KV * dh + H * dh * d
     mlp = 3 * d * ff
-    attn = 2 * H * dh * (seq_len / 2)  # q·k and p·v
-    macs = L * (proj + mlp + attn) + d * V
-    return 6.0 * macs
+    macs = L * (proj + mlp) + d * V
+    return 6.0 * macs + attention_flops_per_token(c, seq_len)

@@ -196,12 +196,13 @@ def host_stall_ms(pt: ProgramTrace) -> Optional[float]:
     return 1e3 * stall / len(pt.steps)
 
 
-def attention_flops_per_token(ref, config, seq_len: int) -> float:
-    """The causal attention term of the dense reference's
-    ``flops_per_token`` (q·k and p·v, forward and backward), taken from it
-    as the part that grows with the sequence."""
-    return (ref.flops_per_token(config, seq_len)
-            - ref.flops_per_token(config, 0))
+def attention_flops_per_token(ref, config, seq_len: int
+                               ) -> Optional[float]:
+    """The causal attention term of the reference's ``flops_per_token``
+    (q·k and p·v, forward and backward), where the reference module names
+    one (its ``attention_flops_per_token``); None where it does not."""
+    term = getattr(ref, "attention_flops_per_token", None)
+    return None if term is None else term(config, seq_len)
 
 
 def attn_roofline(pt: ProgramTrace, flops_per_step: float,
@@ -215,9 +216,9 @@ def attn_roofline(pt: ProgramTrace, flops_per_step: float,
     return 100.0 * flops_per_step * len(pt.steps) / (t * peak_flops_per_s)
 
 
-def step_hlo_text(trainer) -> str:
-    """The optimized HLO of the trainer's step, compiled afresh from the
-    source that runs now, for the shapes its loader feeds.
+def compile_step(trainer):
+    """The trainer's step compiled afresh from the source that runs now,
+    for the shapes its loader feeds: a ``jax.stages.Compiled``.
 
     JAX keys its compilation caches without metadata, so the executable
     they hold may carry the ``op_name`` metadata of another build of the
@@ -233,6 +234,19 @@ def step_hlo_text(trainer) -> str:
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        return trainer.step_fn.lower(trainer.state, batch).compile().as_text()
+        return trainer.step_fn.lower(trainer.state, batch).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def step_hlo_text(trainer) -> str:
+    """The optimized HLO of the trainer's step (``compile_step``)."""
+    return compile_step(trainer).as_text()
+
+
+def memory_bytes(ma) -> Dict[str, int]:
+    """A compiled program's ``memory_analysis()`` by part, in bytes."""
+    return {"arguments": ma.argument_size_in_bytes,
+            "outputs": ma.output_size_in_bytes,
+            "temporaries": ma.temp_size_in_bytes,
+            "aliased": ma.alias_size_in_bytes}

@@ -29,11 +29,10 @@ def _need(ma) -> int:
 
 
 def _row(ma) -> dict:
-    return {"arguments": ma.argument_size_in_bytes,
-            "outputs": ma.output_size_in_bytes,
-            "temporaries": ma.temp_size_in_bytes,
-            "aliased": ma.alias_size_in_bytes, "needed": _need(ma),
-            "limit": V5E_BYTES_LIMIT}
+    from bench import program_trace
+
+    return dict(program_trace.memory_bytes(ma), needed=_need(ma),
+                limit=V5E_BYTES_LIMIT)
 
 
 def rehearse(workload: str, one_chip) -> dict:

@@ -46,3 +46,24 @@ def test_full_size_flops_per_token():
                                f"f_{family}")
         assert ref.flops_per_token(config, 2048) == pytest.approx(want,
                                                                   rel=1e-3)
+
+
+@pytest.mark.parametrize("seq", [64, 2047, 2048, 14336])
+@pytest.mark.parametrize("size", ["tiny", "full"])
+def test_dense_flops_are_the_attention_term_and_the_rest(size, seq):
+    """Naming the attention term left ``flops_per_token`` bit for bit the
+    single sum it was (kept here as written before)."""
+    if size == "tiny":
+        _, c = tiny_config("dense")
+    else:
+        c = json.loads((ROOT / "bench/configs/smollm-360m.json").read_text())
+    dense = spec.load_module(ROOT / "bench/models/dense.py", "b_dense")
+    d, ff, V, L = (c["hidden_size"], c["intermediate_size"], c["vocab_size"],
+                   c["num_hidden_layers"])
+    H, KV, dh = (c["num_attention_heads"], c["num_key_value_heads"],
+                 c["head_dim"])
+    proj = d * H * dh + 2 * d * KV * dh + H * dh * d
+    attn = 2 * H * dh * (seq / 2)
+    before = 6.0 * (L * (proj + 3 * d * ff + attn) + d * V)
+    assert dense.flops_per_token(c, seq) == before
+    assert dense.attention_flops_per_token(c, seq) == 6.0 * L * attn

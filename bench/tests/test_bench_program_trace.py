@@ -256,3 +256,31 @@ def test_attention_work_is_the_reference_term(which):
         want, rel=1e-12)
     if which != "tiny":  # 7 x 2048 tokens a step: 5.41 TFLOP
         assert want * 7 * S == pytest.approx(5.4117e12, rel=1e-4)
+
+
+@pytest.mark.parametrize("family,config", [("dense", "smollm-360m"),
+                                           ("ssm", "mamba2-370m")])
+def test_program_readers_read_the_program_trace(family, config):
+    """The readers take the work from the reference's attention term at the
+    mix's length and the step's tokens; a reference without one (the SSM)
+    reads no roofline, whatever the trace holds."""
+    trace = _constructed()
+    ref = spec.load_module(ROOT / f"bench/models/{family}.py", f"r_{family}")
+    c = json.loads((ROOT / f"bench/configs/{config}.json").read_text())
+    mix = json.loads((ROOT / "bench/traffic/train.seq2k.json").read_text())
+    ctx = {"program": trace, "ref": ref, "config": c, "traffic": mix,
+           "tokens_per_step": mix["rows"] * mix["seq_len"],
+           "peaks": {"bf16_flops_per_s": 1.97e14}}
+    read = {name: spec.load_module(ROOT / f"bench/metrics/{name}.py",
+                                   "m_" + name.replace(".", "_")).read
+            for name in ("attn_roofline.train", "host_stall_ms.train")}
+    assert read["host_stall_ms.train"](ctx) == pytest.approx(16.0)
+    roof = read["attn_roofline.train"](ctx)
+    if family == "dense":
+        work = (ref.attention_flops_per_token(c, mix["seq_len"])
+                * mix["rows"] * mix["seq_len"])
+        assert roof == pt.attn_roofline(trace, work, 1.97e14)
+        assert roof == pytest.approx(100 * work / (0.039 * 1.97e14))
+    else:
+        assert pt.attention_flops_per_token(ref, c, mix["seq_len"]) is None
+        assert roof is None

@@ -65,12 +65,17 @@ def test_busy_is_averaged_over_chips():
     assert tracing.reduce(tr).busy_s == pytest.approx(75e-9)
 
 
-@pytest.mark.parametrize("name", ["train_mfu", "device_idle_share.train"])
+@pytest.mark.parametrize("name", ["train_mfu", "device_idle_share.train",
+                                  "attn_roofline.train",
+                                  "host_stall_ms.train"])
 def test_readers_return_none_when_nothing_to_read(name):
     cell = spec.load_cell("smollm-360m.train.seq2k", ROOT)
     reader = cell.reader(name)
-    assert reader.read({"reduced": None, "flops_per_step": 1.0,
-                        "peaks": {"bf16_flops_per_s": 1.0}}) is None
+    assert reader.read({"reduced": None, "program": None,
+                        "flops_per_step": 1.0, "tokens_per_step": 1,
+                        "peaks": {"bf16_flops_per_s": 1.0},
+                        "ref": cell.reference(), "config": cell.config,
+                        "traffic": cell.traffic}) is None
 
 
 def test_readers_decompose_the_rate():
