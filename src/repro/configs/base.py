@@ -86,7 +86,8 @@ class ArchConfig:
     # --- training-memory knobs (per-arch defaults, overridable by plan) ---
     optimizer: str = "adamw"  # adamw | adafactor
     remat_policy: str = "full"  # none | dots | full (full = save block
-    # boundaries only; required for the large-arch dry-runs to fit HBM)
+    # boundaries and the flash kernel's o and lse where attention runs
+    # it; required for the large-arch dry-runs to fit HBM)
 
     # ------------------------------------------------------------------
     @property

@@ -16,6 +16,10 @@ directions.
 * Forward, grid (B, KVH, n_q, n_k), the kv walk sequential: running max,
   sum and accumulator in f32 VMEM scratch, the row statistics lane-dense
   (rows, 128); outputs o and the per-row logsumexp ``lse`` (B, KVH, G, S).
+  The differentiable entry hands o on as (B, S, H·dh), the layout the
+  output projection reads, and saves it so for the backward: a remat
+  policy that keeps ``FLASH_RESIDUALS`` keeps this o and lse, and the
+  backward then runs no forward kernel.
 * dK/dV, grid (B, KVH, n_k, n_q), the q walk sequential: the scores
   transposed, (block_k, rows), so ``lse`` and D = rowsum(dO ⊙ O)
   broadcast as rows and dv += pᵀ·dO, dk += dsᵀ·q are plain products.
@@ -38,8 +42,11 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.obs import metrics as obs_metrics
 
 NUM_LANES = 128
 NEG_INF = -1e30                # running-max floor: finite, so exp(m − m) = 1
@@ -350,13 +357,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
             dq_ref.dtype)
 
 
-def _backward(qg, k, v, o, lse, do, st: _Static):
+def _backward(qg, k, v, lse, d, do, st: _Static):
+    """dq like qg, dk and dv like k; do is laid out like qg, lse and
+    D = rowsum(dO ⊙ O) (B, KVH, G, S) f32."""
     B, KVH, G, S, dh = qg.shape
     bq, bk = st.block_q, st.block_k
     n_q, n_k = S // bq, k.shape[2] // bk
     scale = 1.0 / math.sqrt(dh)
-    # D = rowsum(dO ⊙ O), laid out like lse
-    d = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     q_shape, stat_shape, kv_shape = (1, 1, G, bq, dh), (1, 1, G, bq), \
         (1, 1, bk, dh)
 
@@ -412,18 +419,48 @@ def _backward(qg, k, v, o, lse, do, st: _Static):
 # ---------------------------------------------------------------------------
 
 
+def _seq_major(o):
+    """o (B, KVH, G, S, dh) -> (B, S, H·dh), head h = kvh·G + g: the
+    layout the output projection reads."""
+    B, KVH, G, S, dh = o.shape
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, S, KVH * G * dh)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _attention(qg, k, v, st: _Static):
-    return _forward(qg, k, v, st)[0]
+    """-> o (B, S, H·dh)."""
+    return _seq_major(_forward(qg, k, v, st)[0])
+
+
+#: the forward's outputs under these names, so that a remat policy that
+#: saves them (``models/transformer.py::_remat``) hands the backward the
+#: kernel's own o and lse instead of running the forward kernel again
+FLASH_RESIDUALS = ("flash_o", "flash_lse")
+
+_NAMED = obs_metrics.REGISTRY.counter(
+    "repro_flash_residuals_named_total",
+    "flash forward rule traces that named o and lse for a remat policy")
 
 
 def _attention_fwd(qg, k, v, st: _Static):
     o, lse = _forward(qg, k, v, st)
+    # o is named, and read by the backward, in the layout the output
+    # projection reads: saving it adds no relayout, and (S, H·dh) rows
+    # fill the lanes where a dh of 64 would fill half of them
+    o = checkpoint_name(_seq_major(o), FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
+    _NAMED.inc()
     return o, (qg, k, v, o, lse)
 
 
 def _attention_bwd(st: _Static, res, do):
-    return _backward(*res, do, st)
+    qg, k, v, o, lse = res
+    B, KVH, G, S, dh = qg.shape
+    heads = lambda x: x.reshape(B, S, KVH, G, dh)
+    # D = rowsum(dO ⊙ O) in the layout both arrive in, then laid out like lse
+    d = jnp.sum(heads(do.astype(jnp.float32) * o.astype(jnp.float32)),
+                axis=-1).transpose(0, 2, 3, 1)
+    return _backward(qg, k, v, lse, d, heads(do).transpose(0, 2, 3, 1, 4), st)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
@@ -456,7 +493,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     (positions).  ``interpret=True`` runs the kernel bodies on the CPU
     (validation); on a TPU pass ``interpret=False``."""
     st = _static(q, k, causal, window, block_q, block_k, interpret)
-    return _attention(_grouped(q, k.shape[1]), k, v, st).reshape(q.shape)
+    B, H, Sq, dh = q.shape
+    o = _attention(_grouped(q, k.shape[1]), k, v, st)
+    return o.reshape(B, Sq, H, dh).transpose(0, 2, 1, 3)
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True,

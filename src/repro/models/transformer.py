@@ -136,12 +136,19 @@ def decode_state_axes(cfg: ArchConfig) -> Dict[str, Any]:
 
 
 def _remat(fn, policy: str):
+    """Checkpoint a block.  Both policies also save the flash forward
+    kernel's o and lse where attention takes the Pallas path, so the
+    backward reads them instead of running that kernel again; on every
+    other path nothing carries those names."""
     if policy in (None, "none"):
         return fn
+    from repro.kernels.flash_attention import FLASH_RESIDUALS
+    cp = jax.checkpoint_policies
+    pol = cp.save_only_these_names(*FLASH_RESIDUALS)
     if policy == "dots":
-        pol = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    else:  # "full" / "nothing": save only block boundaries
-        pol = jax.checkpoint_policies.nothing_saveable
+        pol = cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable, pol)
+    # "full" / "nothing": block boundaries and the named residuals
     return jax.checkpoint(fn, policy=pol)
 
 

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 
 from repro.kernels import ref
 from repro.kernels import ops
@@ -150,6 +151,80 @@ def test_flash_attention_fully_masked_rows_are_zero():
         flash_attention(q, k, v, **kw) * w), (0, 1, 2))(q, k, v)
     assert all(np.isfinite(np.asarray(g)).all() for g in grads)
     np.testing.assert_array_equal(np.asarray(grads[0])[:, :, hidden], 0.0)
+
+
+def _count_eqns(jaxpr, name: str) -> int:
+    """Equations of primitive ``name`` in a jaxpr and every jaxpr in its
+    equations' parameters (each occurrence counted)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else [p]:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    n += _count_eqns(sub, name)
+    return n
+
+
+def test_remat_keeps_the_flash_forward_outputs():
+    """A block checkpointed with ``"full"`` saves the forward kernel's o
+    and lse, so its gradient runs the forward kernel once (forward, dK/dV,
+    dQ: three calls), where saving nothing runs it again (four).  The
+    gradients are those of the unsaved and the unremat'd block, and each
+    trace of the forward rule names its residuals once."""
+    from repro.models.transformer import _remat
+    q, k, v, w = _bhsd(1, 4, 2, 256, 256, 64)
+
+    def block(q, k, v):
+        return jnp.sin(ops.flash_attention(q, k, v, block_q=128,
+                                           block_k=128, interpret=True) * w)
+
+    policies = {
+        "full": _remat(block, "full"),
+        "dots": _remat(block, "dots"),
+        "none": _remat(block, "none"),
+        "nothing_saveable": jax.checkpoint(
+            block, policy=jax.checkpoint_policies.nothing_saveable),
+    }
+    grads = {name: jax.grad(lambda q, k, v, f=f: jnp.sum(f(q, k, v)),
+                            (0, 1, 2)) for name, f in policies.items()}
+    named = obs_metrics.REGISTRY.counter("repro_flash_residuals_named_total")
+    jax.clear_caches()           # the kernel's jit traces its rules afresh
+    before = named.value()
+    jaxprs = {name: jax.make_jaxpr(g)(q, k, v) for name, g in grads.items()}
+    assert named.value() - before == 1
+    calls = {name: _count_eqns(j.jaxpr, "pallas_call")
+             for name, j in jaxprs.items()}
+    assert calls == {"full": 3, "dots": 3, "none": 3, "nothing_saveable": 4}
+    want = grads["full"](q, k, v)
+    for name in ("none", "nothing_saveable"):
+        for a, b in zip(grads[name](q, k, v), want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("chunked", [False, True], ids=["plain", "chunked"])
+@pytest.mark.parametrize("policy,old", [
+    ("full", jax.checkpoint_policies.nothing_saveable),
+    ("dots", jax.checkpoint_policies.dots_with_no_batch_dims_saveable)])
+def test_remat_saves_as_before_off_the_kernel_path(chunked, policy, old,
+                                                   capsys):
+    """The XLA attention paths carry no flash names, so ``_remat`` saves
+    what the plain policy saved there."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from repro.models.transformer import _remat
+    q, k, v = _bshd(1, 512, 4, 2, 64)
+
+    def block(q, k, v):
+        return jnp.sin(attn_mod.attention_core(q, k, v,
+                                               force_chunked=chunked))
+
+    print_saved_residuals(_remat(block, policy), q, k, v)
+    ours = capsys.readouterr().out
+    print_saved_residuals(jax.checkpoint(block, policy=old), q, k, v)
+    assert ours == capsys.readouterr().out and ours
 
 
 def _lowerings():

@@ -10,6 +10,7 @@ file.  Keep these tests in this one file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import pathlib
 
@@ -118,6 +119,43 @@ def test_attention_core_grad_compiles_for_v5e(one_chip, no_persistent_cache,
     bwd = [c for c in calls if "transpose(" in c]
     assert len(fwd) >= 1 and len(bwd) >= 2, (len(fwd), len(bwd))
     assert all("attention_core" in c for c in calls)
+
+
+def test_full_remat_step_runs_the_flash_forward_once_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """jax.grad of smollm-360m's loss at full width (2 layers, 1 × 2048,
+    ``remat_policy="full"``) on the TPU path: the block's checkpoint keeps
+    the forward kernel's o and lse, so the program holds one forward
+    kernel, the custom call whose results include the f32 lse
+    (1, 5 kv heads, 3 query heads each, 2048), and the backward reads its
+    outputs instead of running it again.  The layers' lse is stacked, and
+    o is not stacked in the kernel's (S, d_h) rows, which d_h = 64 would
+    pad to 128 lanes: it is saved in the output projection's layout."""
+    from repro.models import transformer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_arch("smollm-360m"), n_layers=2,
+                              remat_policy="full")
+    B, S = 1, 2048
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        transformer.param_shapes(cfg))
+    ids = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+    batch = {"tokens": ids, "labels": ids}
+
+    def grad(params, batch):
+        return jax.grad(lambda p: transformer.loss_fn(p, cfg, batch)[0])(
+            params)
+
+    text = jax.jit(grad).lower(params, batch).compile().as_text()
+    heads = f"{B},{cfg.n_kv_heads},{cfg.n_heads // cfg.n_kv_heads}"
+    lse = f"f32[{heads},{S}]"
+    results = [line.split("custom-call(")[0] for line in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(results) >= 3, len(results)
+    assert sum(lse in r for r in results) == 1, [
+        r for r in results if lse in r]
+    assert f"f32[{cfg.n_layers},{heads},{S}]" in text      # lse, stacked
+    assert f"bf16[{cfg.n_layers},{heads},{S},{cfg.head_dim_}]" not in text
 
 
 def test_compile_cache_dir_is_fixed_in_checkout(monkeypatch):
